@@ -212,7 +212,11 @@ PAPER_ARCH_IDS = ["gpt3_0_7b", "gpt3_1_3b", "gpt3_2_7b", "gpt3_6_7b",
 
 
 def get_config(arch: str) -> ModelConfig:
+    """Config of an ID in ARCH_IDS or PAPER_ARCH_IDS."""
     arch = arch.replace("-", "_").replace(".", "_")
+    if arch in PAPER_ARCH_IDS:
+        from repro.configs.gpt3_family import get_paper_config
+        return get_paper_config(arch)
     mod = importlib.import_module(f"repro.configs.{arch}")
     return mod.CONFIG
 

@@ -325,24 +325,24 @@ class SerializeArena:
         isz = store_dtype(rec.dtype).itemsize
         return dirty_block % isz == 0 and (dirty_block // isz) % 1024 == 0
 
-    def _fill_record_device(self, leaf, dst, rec, prev2d, dirty,
+    def _fill_record_device(self, leaf, dst, rec, prev, dirty,
                             dirty_block: int):
         """Device-mask snapshot of one record: the Pallas kernel compares
-        the packed image against ``prev2d`` on device, and only dirty
+        the packed image against ``prev`` on device, and only dirty
         blocks (plus the tiny mask) cross PCIe. Clean blocks are skipped
         entirely — valid because the resident arena bytes equal the
         previous packed image (``_image_valid``) and the pack is
-        bit-preserving. Returns (new prev2d, d2h bytes moved)."""
+        bit-preserving. Returns (new prev, d2h bytes moved)."""
         from repro.core.delta import mask_to_spans
         from repro.kernels import ops
         elems = dirty_block // store_dtype(rec.dtype).itemsize
-        packed2d, _amax, mask = ops.ckpt_pack_dirty(leaf, prev2d,
-                                                    block=elems)
+        packed, _amax, mask = ops.ckpt_pack_dirty(leaf, prev, block=elems)
         mask_h = np.asarray(mask)
         d2h = mask_h.nbytes
-        idx = np.flatnonzero(mask_h)
+        # the image pads to whole grid steps; pad blocks are always clean
+        idx = np.flatnonzero(mask_h[:-(-rec.nbytes // dirty_block)])
         if idx.size:
-            rows = np.asarray(packed2d[idx])          # gather: one D2H
+            rows = np.asarray(packed[idx])            # gather: one D2H
             rows8 = np.ascontiguousarray(portable_view(rows)) \
                 .view(np.uint8).reshape(idx.size, dirty_block)
             d2h += rows8.nbytes
@@ -354,7 +354,7 @@ class SerializeArena:
         if dirty is not None:
             dirty.extend((rec.offset + off, length) for off, length
                          in mask_to_spans(mask_h, dirty_block, rec.nbytes))
-        return packed2d, d2h
+        return packed, d2h
 
     def _fill(self, leaves, *, track_dirty: bool, dirty_block: int,
               device_dirty: bool = False,
@@ -390,6 +390,9 @@ class SerializeArena:
                     and self._device_eligible(rec, dirty_block):
                 new_prev[i], nb = self._fill_record_device(
                     leaf, dst, rec, old_prev[i], dirty, dirty_block)
+                # drop each old image as its successor lands, so the
+                # device holds one image of the state, not two
+                old_prev[i] = None
                 d2h += nb
                 if progress is not None:
                     progress.advance(end)

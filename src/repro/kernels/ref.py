@@ -9,9 +9,15 @@ import jax.numpy as jnp
 from repro.models.layers import segsum
 
 
+def _block_axes(x):
+    """Every axis but the leading block index."""
+    return tuple(range(1, x.ndim))
+
+
 def ckpt_pack_ref(x2d, *, out_dtype=jnp.bfloat16, scale=1.0):
+    """x2d: one block per leading index (any trailing shape)."""
     xf = x2d.astype(jnp.float32) * scale
-    return xf.astype(out_dtype), jnp.max(jnp.abs(xf), axis=1)
+    return xf.astype(out_dtype), jnp.max(jnp.abs(xf), axis=_block_axes(xf))
 
 
 _UINTS = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
@@ -30,8 +36,8 @@ def ckpt_pack_dirty_ref(x2d, prev2d, *, out_dtype=None, scale=1.0):
     ubits = _UINTS[jnp.dtype(out_dtype).itemsize]
     yb = jax.lax.bitcast_convert_type(y, ubits)
     pb = jax.lax.bitcast_convert_type(prev2d, ubits)
-    mask = jnp.any(yb != pb, axis=1).astype(jnp.int32)
-    return y, jnp.max(jnp.abs(xf), axis=1), mask
+    mask = jnp.any(yb != pb, axis=_block_axes(yb)).astype(jnp.int32)
+    return y, jnp.max(jnp.abs(xf), axis=_block_axes(xf)), mask
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=None, cap=None):

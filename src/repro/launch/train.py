@@ -4,9 +4,9 @@
         --reduced --steps 50 --batch 8 --seq 128 \
         --ckpt-dir /tmp/ckpt --ckpt-mode fastpersist --every 1 --pipeline
 
-On this CPU container use --reduced; on a TPU pod the full config lowers
-through the same path with the production mesh (see dryrun.py for the
-sharding configuration the full-scale run uses).
+On a CPU host use --reduced; on one TPU chip the paper's models
+(``--arch gpt3_0_7b`` etc.) run at their published widths with depth
+cut to fit (see chip_smoke.py).
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ from repro.configs import get_config, reduced as make_reduced
 from repro.core.checkpointer import FastPersistConfig
 from repro.core.partition import Topology
 from repro.core.writer import WriterConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adam import AdamConfig
 from repro.train.trainer import CheckpointPolicy, Trainer, TrainerConfig
 
@@ -139,6 +140,7 @@ def main():
                        args.restore_readers == "auto"
                        else int(args.restore_readers))
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = make_reduced(cfg)

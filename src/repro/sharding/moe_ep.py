@@ -87,8 +87,16 @@ def _local_moe(p, xt, cfg: ModelConfig, *, model_axis, n_model,
 
 def make_shard_map_moe(mesh, *, model_axis="model"):
     """Returns moe_kernel(p, x, cfg) -> (out, aux) for use inside a model
-    running under ``mesh``. Token batch must be sharded over the data
-    axes; expert weights over ``model``."""
+    running under ``mesh`` (Auto axes: ``repro.launch.mesh.make_mesh``).
+    Token batch must be sharded over the data axes; expert weights over
+    ``model``."""
+    from jax.sharding import AxisType
+    if any(t != AxisType.Auto for t in mesh.axis_types):
+        # outside the shard_map the model relies on GSPMD propagation;
+        # under Explicit axes its gathers are refused
+        raise ValueError(
+            f"expert parallelism needs a mesh with Auto axes, got "
+            f"{mesh.axis_types}; build it with repro.launch.mesh.make_mesh")
     data_axes = tuple(a for a in mesh.axis_names if a != model_axis)
     n_model = mesh.shape[model_axis]
 

@@ -104,6 +104,15 @@ class CheckpointPolicy:
 
 
 @dataclass
+class StepRecord:
+    """What one train step left behind."""
+    step: int
+    loss: object                       # device scalar; float() syncs
+    ckpt_stall: float                  # seconds waiting on checkpoints
+    save: Optional[object] = None      # SaveHandle of this step's save
+
+
+@dataclass
 class TrainerConfig:
     model: ModelConfig
     steps: int = 100
@@ -130,6 +139,8 @@ class Trainer:
         self._retain = None
         self.iter_times = []
         self.ckpt_stall = 0.0
+        #: one :class:`StepRecord` per step run
+        self.history: list = []
         if cfg.checkpoint and cfg.checkpoint.backend_name() != "none":
             self._setup_checkpointer(cfg.checkpoint)
         # back-compat alias: older code/tests reach the checkpointer via
@@ -199,13 +210,15 @@ class Trainer:
             step = step if step is not None else self.engine.latest_step()
             if step is None:
                 return 0
-        if self.state is None:
-            self.init_state()
+        # the pytree structure comes from the abstract state: resume
+        # never materialises a throwaway initial state on the device
+        like = jax.eval_shape(lambda rng: init_train_state(self.model, rng),
+                              jax.random.PRNGKey(self.cfg.seed))
         readers = (self.cfg.checkpoint.restore_readers
                    if self.cfg.checkpoint else None)
         try:
             restored, manifest = self.engine.load(
-                step, like=self.state, parallel=readers, tier=use_tier)
+                step, like=like, parallel=readers, tier=use_tier)
         except FileNotFoundError:
             # only the AUTOMATIC fallback may degrade to a fresh start;
             # an operator who explicitly asked for the peer/remote tier
@@ -215,14 +228,18 @@ class Trainer:
             if use_tier != "local" and step is None and not forced:
                 return 0                # no tier has a checkpoint
             raise
+        # release the old state (and the arena's device-resident
+        # images) BEFORE the host->device copy, so the device holds one
+        # copy of the state at any time. Donation hook too: the state
+        # object is replaced wholesale — invalidate the serialize
+        # arena's cached layout explicitly rather than trusting the
+        # structure key to notice
+        self.state = None
+        self.engine.invalidate_arena()
         # jnp.array COPIES: a parallel load returns views into the
         # engine's read arena, which the next load would refill —
         # the trainer's state must own its buffers (DESIGN.md §7)
         self.state = jax.tree.map(jax.numpy.array, restored)
-        # donation hook: the state object was just replaced wholesale —
-        # invalidate the serialize arena's cached layout explicitly
-        # rather than trusting the structure key to notice
-        self.engine.invalidate_arena()
         extras = manifest.extras
         if "data" in extras:
             self.data = TokenStream.from_state(self.data.cfg, extras["data"])
@@ -231,7 +248,7 @@ class Trainer:
     # ------------------------------------------------------------- loop
     def _save(self, step: int):
         extras = {"step": step, "data": self.data.state()}
-        self.engine.save(self.state, step, extras)
+        return self.engine.save(self.state, step, extras)
 
     def run(self, start_step: int = 0):
         if self.state is None:
@@ -240,6 +257,8 @@ class Trainer:
         metrics = {}
         for step in range(start_step, self.cfg.steps):
             t0 = time.perf_counter()
+            stall0 = self.ckpt_stall
+            handle = None
             batch = next(self.data)
             if self.engine is not None and self.engine.async_save:
                 # §4.3 sync point, chunk-granular (DESIGN.md §10): the
@@ -256,10 +275,12 @@ class Trainer:
             if pol and self.engine is not None \
                     and (step + 1) % pol.every == 0:
                 jax.block_until_ready(self.state.params)
-                self._save(step + 1)
+                handle = self._save(step + 1)
                 if self._retain is not None:
                     self._retain.after_commit()
             self.iter_times.append(time.perf_counter() - t0)
+            self.history.append(StepRecord(step + 1, metrics["loss"],
+                                           self.ckpt_stall - stall0, handle))
             if (step + 1) % self.cfg.log_every == 0:
                 print(f"step {step+1}: loss={float(metrics['loss']):.4f} "
                       f"it={np.mean(self.iter_times[-self.cfg.log_every:])*1e3:.1f}ms")

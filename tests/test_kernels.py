@@ -116,8 +116,10 @@ def test_ckpt_pack_dirty_nan_stable():
 
 
 def test_ckpt_pack_dirty_shape_mismatch():
+    # images pad to whole grid steps (128 blocks here), so the two
+    # tensors must differ by more than that to get different images
     x = _rand((2048,), jnp.float32)
-    prev2d = ops.pack_blocks(_rand((4096,), jnp.float32), block=1024)
+    prev2d = ops.pack_blocks(_rand((129 * 1024,), jnp.float32), block=1024)
     with pytest.raises(ValueError):
         ops.ckpt_pack_dirty(x, prev2d, block=1024)
 

@@ -7,7 +7,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 _SCRIPT = r"""
@@ -16,9 +15,10 @@ os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
 import dataclasses, jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, reduced
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model, make_batch
 
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+mesh = make_mesh((2, 4), ('data', 'model'))
 for arch in ('qwen3_moe_235b', 'arctic_480b'):
     base = reduced(get_config(arch))
     base = dataclasses.replace(
@@ -41,9 +41,6 @@ print('ALL-OK')
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="jax in this environment lacks jax.shard_map "
-                           "(moe_ep.moe_kernel needs it)")
 def test_shard_map_moe_matches_einsum_on_8_devices():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -53,3 +50,16 @@ def test_shard_map_moe_matches_einsum_on_8_devices():
                        capture_output=True, text=True, timeout=500)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "ALL-OK" in r.stdout
+
+
+def test_shard_map_moe_refuses_explicit_mesh():
+    """jax.make_mesh's default Explicit axes break the model's GSPMD
+    code outside the shard_map; the kernel factory says so up front."""
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.sharding.moe_ep import make_shard_map_moe
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Explicit,) * 2)
+    with pytest.raises(ValueError, match="Auto"):
+        make_shard_map_moe(mesh)

@@ -3,7 +3,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import PAPER_TABLE2, get_paper_config, reduced
+from repro.configs import (ARCH_IDS, PAPER_TABLE2, get_config,
+                           get_paper_config, reduced)
+from repro.configs.base import PAPER_ARCH_IDS
 from repro.models.registry import build_model, make_batch
 from repro.optim.adam import AdamConfig
 from repro.train.steps import init_train_state, make_train_step
@@ -57,3 +59,11 @@ def test_ce_chunking_equals_full():
     l1 = float(jax.jit(m_full.loss)(p, batch))
     l2 = float(jax.jit(m_chunk.loss)(p, batch))
     assert l1 == pytest.approx(l2, rel=1e-5)
+
+
+@pytest.mark.parametrize("arch", [*ARCH_IDS, *PAPER_ARCH_IDS])
+def test_get_config_resolves_every_id(arch):
+    """The launcher's --arch takes the assigned and the paper's IDs."""
+    cfg = get_config(arch)
+    assert cfg.n_layers > 0 and cfg.d_model > 0
+    assert get_config(arch.replace("_", "-")) == cfg

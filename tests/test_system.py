@@ -85,3 +85,36 @@ def test_moe_trainer_with_checkpointing(tmp_path):
     assert bool(jnp.isfinite(m["loss"]))
     t2 = Trainer(_tc(tmp_path, cfg, 3))
     assert t2.restore() == 3
+
+
+def test_restore_holds_one_copy_of_the_state(tmp_path, monkeypatch):
+    """Resume never materialises a throwaway initial state: the pytree
+    structure comes from an abstract trace (model.init and
+    init_train_state only ever see tracers, never a concrete key), and
+    the restored state is bit-exact."""
+    import repro.train.trainer as trainer_mod
+    cfg = reduced(get_config("stablelm_1_6b"))
+    t_a = Trainer(_tc(tmp_path, cfg, 3))
+    t_a.run()
+
+    t_b = Trainer(_tc(tmp_path, cfg, 3))
+    ran = []
+    real_init, real_its = t_b.model.init, trainer_mod.init_train_state
+
+    def spy_init(rng):
+        ran.append(("model.init", not isinstance(rng, jax.core.Tracer)))
+        return real_init(rng)
+
+    def spy_its(model, rng):
+        ran.append(("init_train_state",
+                    not isinstance(rng, jax.core.Tracer)))
+        return real_its(model, rng)
+
+    t_b.model = dataclasses.replace(t_b.model, init=spy_init)
+    monkeypatch.setattr(trainer_mod, "init_train_state", spy_its)
+    assert t_b.restore() == 3
+    assert ran and not any(concrete for _, concrete in ran), ran
+    for a, b in zip(jax.tree.leaves(t_a.state), jax.tree.leaves(t_b.state)):
+        assert isinstance(b, jax.Array)
+        np.testing.assert_array_equal(np.atleast_1d(a).view(np.uint8),
+                                      np.atleast_1d(b).view(np.uint8))
