@@ -38,11 +38,11 @@ the NEXT load on the same arena; copy them (``jnp.array`` /
 from __future__ import annotations
 
 import threading
-import time
 from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.serializer import (Manifest, TensorRecord, _path_str,
                                    portable_view, store_dtype)
 from repro.core.writer import aligned_buffer
@@ -85,14 +85,21 @@ class _LeafBytes:
                 self._flat = None
 
     def range(self, lo: int, hi: int) -> np.ndarray:
-        """uint8 view/copy of stream bytes [lo, hi) of this leaf."""
+        """uint8 view/copy of stream bytes [lo, hi) of this leaf.
+        Spans: ``snapshot.slice`` (dispatch of the piece's device slice)
+        and ``snapshot.xfer`` (the blocking device-to-host transfer)."""
         partial = not (lo == 0 and hi == self._n)
         if (self._flat is not None and partial and self._isz
                 and lo % self._isz == 0 and hi % self._isz == 0):
-            piece = _host_array(self._flat[lo // self._isz:hi // self._isz])
-            return np.ascontiguousarray(piece).reshape(-1).view(np.uint8)
+            with obs.span("snapshot.slice"):
+                piece = self._flat[lo // self._isz:hi // self._isz]
+            with obs.span("snapshot.xfer"):
+                piece = np.ascontiguousarray(_host_array(piece))
+            return piece.reshape(-1).view(np.uint8)
         if self._host is None:
-            self._host = _host_array(self._leaf).reshape(-1).view(np.uint8)
+            with obs.span("snapshot.xfer"):
+                self._host = _host_array(self._leaf).reshape(-1).view(
+                    np.uint8)
         return self._host[lo:hi]
 
 
@@ -337,20 +344,23 @@ class SerializeArena:
         from repro.kernels import ops
         elems = dirty_block // store_dtype(rec.dtype).itemsize
         packed, _amax, mask = ops.ckpt_pack_dirty(leaf, prev, block=elems)
-        mask_h = np.asarray(mask)
+        with obs.span("snapshot.xfer"):
+            mask_h = np.asarray(mask)
         d2h = mask_h.nbytes
         # the image pads to whole grid steps; pad blocks are always clean
         idx = np.flatnonzero(mask_h[:-(-rec.nbytes // dirty_block)])
         if idx.size:
-            rows = np.asarray(packed[idx])            # gather: one D2H
+            with obs.span("snapshot.xfer"):
+                rows = np.asarray(packed[idx])        # gather: one D2H
             rows8 = np.ascontiguousarray(portable_view(rows)) \
                 .view(np.uint8).reshape(idx.size, dirty_block)
             d2h += rows8.nbytes
             dst8 = dst.reshape(-1).view(np.uint8)
-            for k, b in enumerate(idx.tolist()):
-                lo = b * dirty_block
-                hi = min(lo + dirty_block, rec.nbytes)
-                dst8[lo:hi] = rows8[k, :hi - lo]
+            with obs.span("snapshot.copy"):
+                for k, b in enumerate(idx.tolist()):
+                    lo = b * dirty_block
+                    hi = min(lo + dirty_block, rec.nbytes)
+                    dst8[lo:hi] = rows8[k, :hi - lo]
         if dirty is not None:
             dirty.extend((rec.offset + off, length) for off, length
                          in mask_to_spans(mask_h, dirty_block, rec.nbytes))
@@ -419,7 +429,8 @@ class SerializeArena:
                                              rec_spans[-1][1] + length)
                         else:
                             rec_spans.append((off, length))
-                dst8[lo:hi] = pb
+                with obs.span("snapshot.copy"):
+                    dst8[lo:hi] = pb
                 if progress is not None:
                     progress.advance(rec.offset + hi)
                 lo = hi
@@ -437,6 +448,7 @@ class SerializeArena:
         self.last_dirty_bytes = (sum(ln for _, ln in dirty)
                                  if dirty is not None else None)
         self.last_d2h_bytes = d2h
+        obs.count("snapshot.bytes", d2h)
         self._image_valid = True
 
     def serialize(self, leaves, treedef, track_dirty: bool = False,
@@ -467,7 +479,7 @@ class SerializeArena:
 
     def begin_snapshot(self, leaves, treedef, chunk_bytes: int, *,
                        track_dirty: bool = False, dirty_block: int = 4096,
-                       device_dirty: bool = False):
+                       device_dirty: bool = False, step: int = -1):
         """Chunked-snapshot entry (DESIGN.md §10): lay out the stream
         (metadata only — no device transfer) and return
         ``(manifest, buffers, progress, fill)`` WITHOUT copying a byte.
@@ -478,7 +490,9 @@ class SerializeArena:
         chunks while later tensors are still leaving the device.
         ``fill`` never raises: failures land in ``progress`` and
         re-raise at every ``wait_*`` site, which is how a mid-snapshot
-        death aborts the writers before COMMIT."""
+        death aborts the writers before COMMIT. The fill is the span
+        ``snapshot.fill`` (tagged ``step``), whose seconds become
+        ``progress.seconds``."""
         self._prepare(leaves, treedef)
         progress = SnapshotProgress(self._total, chunk_bytes)
         manifest = Manifest(self._records, self._total,
@@ -486,17 +500,18 @@ class SerializeArena:
         buffers = list(self._buffers)
 
         def fill():
-            t0 = time.perf_counter()
+            sp = obs.span("snapshot.fill", step=step)
             try:
-                self._fill(leaves, track_dirty=track_dirty,
-                           dirty_block=dirty_block,
-                           device_dirty=device_dirty, progress=progress,
-                           chunk_bytes=chunk_bytes)
+                with sp:
+                    self._fill(leaves, track_dirty=track_dirty,
+                               dirty_block=dirty_block,
+                               device_dirty=device_dirty, progress=progress,
+                               chunk_bytes=chunk_bytes)
             except BaseException as exc:   # noqa: BLE001 — parked, re-raised
-                progress.seconds = time.perf_counter() - t0
+                progress.seconds = sp.seconds
                 progress.fail(exc)
             else:
-                progress.seconds = time.perf_counter() - t0
+                progress.seconds = sp.seconds
                 progress.finish()
 
         return manifest, buffers, progress, fill

@@ -42,6 +42,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core import layout
 from repro.core.arena import SerializeArena
 from repro.core.delta import (DeltaPlan, apply_delta, assign_span_shards,
@@ -330,7 +331,7 @@ class FastPersistCheckpointer:
             manifest, buffers, progress, fill = begin_snapshot(
                 state, self._arena, chunk_bytes, track_dirty=track,
                 dirty_block=self.config.dirty_block,
-                device_dirty=device_dirty)
+                device_dirty=device_dirty, step=step)
 
             def _fill_job():
                 fill()                     # failures park in `progress`
@@ -341,10 +342,11 @@ class FastPersistCheckpointer:
                                            name="fp-snapshot", daemon=True)
             fill_thread.start()
         else:
-            manifest, buffers = serialize(
-                state, arena=self._arena, track_dirty=track,
-                dirty_block=self.config.dirty_block,
-                device_dirty=device_dirty)
+            with obs.span("snapshot.fill", step=step) as fill_span:
+                manifest, buffers = serialize(
+                    state, arena=self._arena, track_dirty=track,
+                    dirty_block=self.config.dirty_block,
+                    device_dirty=device_dirty)
             if notify is not None:
                 notify()
         arena_reused = bool(self._arena and self._arena.last_reused)
@@ -553,7 +555,7 @@ class FastPersistCheckpointer:
                                      else manifest.total_bytes),
                           snapshot_seconds=(progress.seconds
                                             if progress is not None
-                                            else ser_s),
+                                            else fill_span.seconds),
                           snapshot_chunks=(progress.n_chunks
                                            if progress is not None else 0),
                           delta_striped=(None if dplan is None
@@ -625,19 +627,23 @@ class FastPersistCheckpointer:
     def _materialize(self, manifest: Manifest, stream, like):
         """Shared tail of every load path: (de)quantize + rebuild arrays
         from an assembled stream. With a memoryview stream the arrays
-        are zero-copy views into it (arena lifetime rule, DESIGN.md §7)."""
-        if manifest.extras.get("quantized"):
-            from repro.core.quant import dequantize_named
-            named = deserialize(manifest, stream)
-            named = dequantize_named(named, manifest)
-            if like is not None:
-                import jax
-                from repro.core.serializer import _path_str
-                leaves, treedef = jax.tree_util.tree_flatten_with_path(like)
-                new = [named[_path_str(p)] for p, _ in leaves]
-                return jax.tree_util.tree_unflatten(treedef, new), manifest
-            return named, manifest
-        return deserialize(manifest, stream, like=like), manifest
+        are zero-copy views into it (arena lifetime rule, DESIGN.md §7).
+        Span ``load.materialize``."""
+        with obs.span("load.materialize"):
+            if manifest.extras.get("quantized"):
+                from repro.core.quant import dequantize_named
+                named = deserialize(manifest, stream)
+                named = dequantize_named(named, manifest)
+                if like is not None:
+                    import jax
+                    from repro.core.serializer import _path_str
+                    leaves, treedef = \
+                        jax.tree_util.tree_flatten_with_path(like)
+                    new = [named[_path_str(p)] for p, _ in leaves]
+                    return (jax.tree_util.tree_unflatten(treedef, new),
+                            manifest)
+                return named, manifest
+            return deserialize(manifest, stream, like=like), manifest
 
     def load(self, step: int, like=None, verify: bool = True,
              directory: Optional[str] = None,
@@ -872,21 +878,22 @@ class FastPersistCheckpointer:
         no re-read) and compare against the manifest. Shards whose
         collected spans do not tile the whole shard (owned-only reads)
         are skipped: a partial read cannot be checked against a
-        whole-shard CRC."""
-        for e in extents:
-            if "crc32" not in e:
-                continue
-            parts = collected.get(int(e["shard_index"]))
-            if not parts:
-                continue
-            combined = combine_span_crcs(parts, int(e["length"]))
-            if combined is None:        # partial coverage: unverifiable
-                continue
-            if combined != e["crc32"]:
-                raise IOError(
-                    f"checkpoint corruption: shard {e['shard_index']} "
-                    f"combined span crc {combined:#x} != manifest "
-                    f"{e['crc32']:#x} (parallel restore path)")
+        whole-shard CRC. Span ``load.verify``."""
+        with obs.span("load.verify"):
+            for e in extents:
+                if "crc32" not in e:
+                    continue
+                parts = collected.get(int(e["shard_index"]))
+                if not parts:
+                    continue
+                combined = combine_span_crcs(parts, int(e["length"]))
+                if combined is None:        # partial coverage: unverifiable
+                    continue
+                if combined != e["crc32"]:
+                    raise IOError(
+                        f"checkpoint corruption: shard {e['shard_index']} "
+                        f"combined span crc {combined:#x} != manifest "
+                        f"{e['crc32']:#x} (parallel restore path)")
 
     def _fill_parallel(self, plan: dict, index: Optional[dict], read_plan,
                        verify, d: str, marker, volume_roots,
@@ -895,7 +902,8 @@ class FastPersistCheckpointer:
         single-host stand-in for the paper's allgather: every rank's
         spans land at their stream offsets, so assembly IS
         concatenation), with combined-CRC verification. Shared by the
-        full parallel load and the keyframe half of a delta restore."""
+        full parallel load and the keyframe half of a delta restore.
+        Span ``load.read``: the readers' wall time."""
         rp = self._resolve_read_plan(read_plan, plan, index)
         rcfg = self.config.writer
         if rcfg.checksum != bool(verify):
@@ -904,17 +912,19 @@ class FastPersistCheckpointer:
         collected: Dict[int, list] = {}
         lock = threading.Lock()
         readers = [r for r in rp.readers if rp.spans_of(r)]
-        if len(readers) <= 1:
-            for r in readers:
-                self._read_rank_spans(r, rp, by_shard, dest, d, marker,
-                                      volume_roots, rcfg, collected, lock)
-        else:
-            with ThreadPoolExecutor(len(readers),
-                                    thread_name_prefix="fp-read") as ex:
-                list(ex.map(
-                    lambda r: self._read_rank_spans(
-                        r, rp, by_shard, dest, d, marker, volume_roots,
-                        rcfg, collected, lock), readers))
+        with obs.span("load.read"):
+            if len(readers) <= 1:
+                for r in readers:
+                    self._read_rank_spans(r, rp, by_shard, dest, d, marker,
+                                          volume_roots, rcfg, collected,
+                                          lock)
+            else:
+                with ThreadPoolExecutor(len(readers),
+                                        thread_name_prefix="fp-read") as ex:
+                    list(ex.map(
+                        lambda r: self._read_rank_spans(
+                            r, rp, by_shard, dest, d, marker, volume_roots,
+                            rcfg, collected, lock), readers))
         if verify:
             self._verify_span_crcs(plan["extents"], collected)
 
@@ -922,10 +932,13 @@ class FastPersistCheckpointer:
                        index: Optional[dict], read_plan, like, verify,
                        d: str, marker, volume_roots):
         """N local reader workers → one shared arena buffer, combined-CRC
-        verification, zero-copy deserialize."""
+        verification, zero-copy deserialize. Span ``load.alloc``: the
+        read buffer."""
         total = manifest.total_bytes
-        dest = (self._arena.read_buffer(total) if self._arena is not None
-                else memoryview(bytearray(total)))
+        with obs.span("load.alloc"):
+            dest = (self._arena.read_buffer(total)
+                    if self._arena is not None
+                    else memoryview(bytearray(total)))
         self._fill_parallel(plan, index, read_plan, verify, d, marker,
                             volume_roots, dest)
         return self._materialize(manifest, dest, like)

@@ -41,6 +41,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core import layout
 from repro.core.baseline import BaselineCheckpointer
 from repro.core.checkpointer import (FastPersistCheckpointer,
@@ -655,25 +656,32 @@ class CheckpointEngine:
         """Persist a checkpoint of ``state`` at ``step``. Returns a
         :class:`SaveHandle`; for sync backends it is already done (and
         errors raise immediately), for async backends it completes when
-        the helper thread commits."""
-        handle = SaveHandle(step, self.spec.backend)
-        job = lambda: self._save_committed(state, step, extras,  # noqa: E731
-                                           handle)
-        self.stats.submitted += 1
-        if self._backend.async_save:
-            if self._worker is None:
-                self._worker = _SaveWorker()
-            self._throttle()
-            with self._lock:
-                self._inflight.append(handle)
-            self._worker.submit(job, handle)
+        the helper thread commits.
+
+        The call is the span ``engine.save`` (tagged ``step``); a save
+        that waits for an earlier one to commit spends that wait in its
+        child ``engine.throttle``. The save worker's spans of the same
+        save, ``save.persist`` and ``save.commit``, carry the same
+        ``step``."""
+        with obs.span("engine.save", step=step):
+            handle = SaveHandle(step, self.spec.backend)
+            job = lambda: self._save_committed(  # noqa: E731
+                state, step, extras, handle)
+            self.stats.submitted += 1
+            if self._backend.async_save:
+                if self._worker is None:
+                    self._worker = _SaveWorker()
+                self._throttle(step)
+                with self._lock:
+                    self._inflight.append(handle)
+                self._worker.submit(job, handle)
+                return handle
+            try:
+                handle._finish(stats=job())  # failures counted inside job
+            except BaseException as e:
+                handle._finish(exc=e)
+                raise
             return handle
-        try:
-            handle._finish(stats=job())      # failures counted inside job
-        except BaseException as e:
-            handle._finish(exc=e)
-            raise
-        return handle
 
     def _warn_if_legacy_only(self):
         """Pre-engine checkpoints (manifest.json, no COMMIT) are
@@ -705,16 +713,16 @@ class CheckpointEngine:
         self._inflight = pending
         return pending
 
-    def _throttle(self):
+    def _throttle(self, step: int):
         """Bound in-flight async saves (memory: each holds a snapshot)."""
-        t0 = time.perf_counter()
-        while True:
-            with self._lock:
-                pending = self._prune_inflight_locked()
-                if len(pending) < self.spec.max_outstanding:
-                    break
-            pending[0]._done.wait()
-        self.stats.stall_seconds += time.perf_counter() - t0
+        with obs.span("engine.throttle", step=step) as sp:
+            while True:
+                with self._lock:
+                    pending = self._prune_inflight_locked()
+                    if len(pending) < self.spec.max_outstanding:
+                        break
+                pending[0]._done.wait()
+        self.stats.stall_seconds += sp.seconds
 
     def _save_committed(self, state, step: int, extras: Optional[dict],
                         handle: Optional[SaveHandle] = None) -> SaveStats:
@@ -774,48 +782,51 @@ class CheckpointEngine:
             self._backend.bind_snapshot(handle._mark_snapshot)
         published = False
         try:
-            stats = self._backend.write_payload_sharded(
-                state, step, extras, staging, volume_staging)
-            t0 = time.perf_counter()
-            # a volume-agnostic backend (baseline, single_file) leaves
-            # its secondary staging dirs empty: drop them instead of
-            # publishing and commit-recording empty generation dirs
-            live = []
-            for s, f in gen_by_root.values():
-                if os.listdir(s):
-                    live.append((s, f))
-                else:
-                    os.rmdir(s)
-            if self.spec.fsync_commit:
-                # the bytes COMMIT vouches for must be durable first —
-                # otherwise power loss can keep the marker, drop the
-                # data; volumes drain concurrently, one flusher per file
-                layout.fsync_payloads([staging, *(s for s, _ in live)])
-            if len(live) > 1:
-                # publish every volume's shard dir concurrently — each
-                # rename + parent fsync is an independent journal commit
-                from concurrent.futures import ThreadPoolExecutor
-                with ThreadPoolExecutor(len(live)) as ex:
-                    list(ex.map(
-                        lambda sf: layout.publish_fresh(
-                            *sf, fsync=self.spec.fsync_commit), live))
-            elif live:
-                layout.publish_fresh(*live[0], fsync=self.spec.fsync_commit)
-            live_staging = {s for s, _ in live}
-            volume_dirs = {str(v): os.path.basename(f)
-                           for v, (s, f) in sorted(secondary.items())
-                           if s in live_staging}
-            marker = layout.write_commit_marker(
-                staging, step, self.spec.backend,
-                fsync=self.spec.fsync_commit,
-                shards=getattr(stats, "shards", None),
-                volume_roots=roots if volume_dirs else None,
-                volume_dirs=volume_dirs or None,
-                generation=getattr(stats, "generation", "") or None,
-                delta=getattr(stats, "delta", None))
-            layout.publish(staging, final, fsync=self.spec.fsync_commit)
-            published = True
-            stats.commit_seconds = time.perf_counter() - t0
+            with obs.span("save.persist", step=step):
+                stats = self._backend.write_payload_sharded(
+                    state, step, extras, staging, volume_staging)
+            with obs.span("save.commit", step=step) as commit:
+                # a volume-agnostic backend (baseline, single_file) leaves
+                # its secondary staging dirs empty: drop them instead of
+                # publishing and commit-recording empty generation dirs
+                live = []
+                for s, f in gen_by_root.values():
+                    if os.listdir(s):
+                        live.append((s, f))
+                    else:
+                        os.rmdir(s)
+                if self.spec.fsync_commit:
+                    # the bytes COMMIT vouches for must be durable first —
+                    # otherwise power loss can keep the marker, drop the
+                    # data; volumes drain concurrently, one flusher a file
+                    layout.fsync_payloads([staging, *(s for s, _ in live)])
+                if len(live) > 1:
+                    # publish every volume's shard dir concurrently — each
+                    # rename + parent fsync is an independent journal
+                    # commit
+                    from concurrent.futures import ThreadPoolExecutor
+                    with ThreadPoolExecutor(len(live)) as ex:
+                        list(ex.map(
+                            lambda sf: layout.publish_fresh(
+                                *sf, fsync=self.spec.fsync_commit), live))
+                elif live:
+                    layout.publish_fresh(*live[0],
+                                         fsync=self.spec.fsync_commit)
+                live_staging = {s for s, _ in live}
+                volume_dirs = {str(v): os.path.basename(f)
+                               for v, (s, f) in sorted(secondary.items())
+                               if s in live_staging}
+                marker = layout.write_commit_marker(
+                    staging, step, self.spec.backend,
+                    fsync=self.spec.fsync_commit,
+                    shards=getattr(stats, "shards", None),
+                    volume_roots=roots if volume_dirs else None,
+                    volume_dirs=volume_dirs or None,
+                    generation=getattr(stats, "generation", "") or None,
+                    delta=getattr(stats, "delta", None))
+                layout.publish(staging, final, fsync=self.spec.fsync_commit)
+                published = True
+            stats.commit_seconds = commit.seconds
         except BaseException:
             # graceful-failure path; a SIGKILL leaves the .tmp dirs and
             # unreferenced generation dirs, which every reader ignores
@@ -863,16 +874,16 @@ class CheckpointEngine:
     def wait(self):
         """Block until every submitted save has committed (the paper's
         block-before-optimizer sync point). Re-raises the first failure.
-        No-op for sync backends."""
-        t0 = time.perf_counter()
-        with self._lock:
-            pending, self._inflight = self._inflight, []
-            err, self._deferred_exc = self._deferred_exc, None
-        for h in pending:
-            h._done.wait()
-            if err is None and h.exception() is not None:
-                err = h.exception()
-        self.stats.stall_seconds += time.perf_counter() - t0
+        No-op for sync backends. Span ``engine.wait``."""
+        with obs.span("engine.wait") as sp:
+            with self._lock:
+                pending, self._inflight = self._inflight, []
+                err, self._deferred_exc = self._deferred_exc, None
+            for h in pending:
+                h._done.wait()
+                if err is None and h.exception() is not None:
+                    err = h.exception()
+        self.stats.stall_seconds += sp.seconds
         if err is not None:
             raise err
 
@@ -883,34 +894,36 @@ class CheckpointEngine:
         the state's device buffers while the WRITES still overlap its
         forward/backward; full commits are still awaited by the save
         throttle, :meth:`wait` and :meth:`drain`. Re-raises the first
-        failure of an already-failed save. No-op for sync backends."""
-        t0 = time.perf_counter()
-        with self._lock:
-            pending = list(self._prune_inflight_locked())
-            err, self._deferred_exc = self._deferred_exc, None
-        for h in pending:
-            h._snapshotted.wait()
-            if err is None and h.done() and h._exc is not None:
-                err = h._exc
-                with self._lock:
-                    if h in self._inflight:
-                        self._inflight.remove(h)
-        dt = time.perf_counter() - t0
-        self.stats.stall_seconds += dt
-        self.stats.snapshot_stall_seconds += dt
+        failure of an already-failed save. No-op for sync backends.
+        Span ``engine.wait_snapshot``; returns the seconds it blocked."""
+        with obs.span("engine.wait_snapshot") as sp:
+            with self._lock:
+                pending = list(self._prune_inflight_locked())
+                err, self._deferred_exc = self._deferred_exc, None
+            for h in pending:
+                h._snapshotted.wait()
+                if err is None and h.done() and h._exc is not None:
+                    err = h._exc
+                    with self._lock:
+                        if h in self._inflight:
+                            self._inflight.remove(h)
+        self.stats.stall_seconds += sp.seconds
+        self.stats.snapshot_stall_seconds += sp.seconds
         if err is not None:
             raise err
+        return sp.seconds
 
     def drain(self):
         """wait() plus parking the helper thread — no thread outlives
         the work. The engine stays fully usable; the next async save
-        restarts the worker."""
-        try:
-            self.wait()
-        finally:
-            if self._worker is not None:
-                self._worker.close()
-                self._worker = None
+        restarts the worker. Span ``engine.drain``."""
+        with obs.span("engine.drain"):
+            try:
+                self.wait()
+            finally:
+                if self._worker is not None:
+                    self._worker.close()
+                    self._worker = None
 
     def close(self):
         """Drain outstanding saves, stop the helper thread, and close

@@ -36,6 +36,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core import aio
 
 
@@ -69,7 +70,12 @@ def read_stream(path: str, spans: Sequence[Tuple[int, int, int]],
     ``checksum`` knobs — one tuning surface for both directions). Spans
     larger than ``io_buffer_size`` are split into multiple in-flight
     submissions; bytes always land at their exact ``dest_offset``, so
-    concurrent readers of DIFFERENT spans may share one ``dest``."""
+    concurrent readers of DIFFERENT spans may share one ``dest``.
+
+    Each chunk's submission is the span ``read.submit`` (a buffered read
+    through libaio completes inside it), its completion wait
+    ``read.wait`` and its CRC fold ``read.crc`` (the last two sums fill
+    ``ReadStats``); the bytes read add to the counter ``read.bytes``."""
     stats = ReadStats(n_spans=len(spans))
     backend = aio.resolve_backend(config.backend)
     stats.backend = backend
@@ -85,15 +91,15 @@ def read_stream(path: str, spans: Sequence[Tuple[int, int, int]],
 
     def complete_one():
         ticket, si, lo, ln = inflight.popleft()
-        t0 = time.perf_counter()
-        sub.wait(ticket)
-        stats.wait_seconds += time.perf_counter() - t0
+        with obs.span("read.wait") as sp:
+            sub.wait(ticket)
+        stats.wait_seconds += sp.seconds
         if crcs is not None:
-            tc = time.perf_counter()
             # chunks of one span are waited for in submission (= file)
             # order, so the running fold equals the span's stream CRC
-            crcs[si] = zlib.crc32(mv[lo:lo + ln], crcs[si])
-            stats.crc_seconds += time.perf_counter() - tc
+            with obs.span("read.crc") as sp:
+                crcs[si] = zlib.crc32(mv[lo:lo + ln], crcs[si])
+            stats.crc_seconds += sp.seconds
 
     t0 = time.perf_counter()
     try:
@@ -104,7 +110,9 @@ def read_stream(path: str, spans: Sequence[Tuple[int, int, int]],
                 while len(inflight) >= depth:
                     complete_one()
                 lo = dest_off + done
-                ticket = sub.submit_read(mv[lo:lo + take], file_off + done)
+                with obs.span("read.submit"):
+                    ticket = sub.submit_read(mv[lo:lo + take],
+                                             file_off + done)
                 inflight.append((ticket, si, lo, take))
                 done += take
                 stats.bytes_read += take
@@ -117,6 +125,7 @@ def read_stream(path: str, spans: Sequence[Tuple[int, int, int]],
     stats.seconds = time.perf_counter() - t0
     stats.n_reads = sub.n_reads
     stats.span_crcs = crcs
+    obs.count("read.bytes", stats.bytes_read)
     return stats
 
 

@@ -134,7 +134,7 @@ def serialize(state, arena=None, track_dirty: bool = False,
 
 def begin_snapshot(state, arena, chunk_bytes: int, *,
                    track_dirty: bool = False, dirty_block: int = 4096,
-                   device_dirty: bool = False):
+                   device_dirty: bool = False, step: int = -1):
     """Chunked-snapshot variant of :func:`serialize` (DESIGN.md §10):
     lays out the stream against ``arena`` without copying and returns
     ``(manifest, buffers, progress, fill)`` — the caller runs ``fill``
@@ -145,7 +145,7 @@ def begin_snapshot(state, arena, chunk_bytes: int, *,
     return arena.begin_snapshot(leaves, treedef, chunk_bytes,
                                 track_dirty=track_dirty,
                                 dirty_block=dirty_block,
-                                device_dirty=device_dirty)
+                                device_dirty=device_dirty, step=step)
 
 
 def decode_record(rec: TensorRecord, raw) -> np.ndarray:

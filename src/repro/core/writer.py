@@ -37,6 +37,7 @@ import zlib
 from dataclasses import dataclass
 from typing import Iterable, Optional
 
+from repro import obs
 from repro.core import aio
 
 DEFAULT_ALIGN = 4096
@@ -107,8 +108,15 @@ class WriteStats:
 def write_stream(path: str, segments: Iterable[memoryview], total: int,
                  config: WriterConfig, file_offset: int = 0) -> WriteStats:
     """Write ``segments`` (in order, ``total`` bytes) to ``path`` starting
-    at ``file_offset`` using the FastPersist §4.1 write path."""
-    cfg = config
+    at ``file_offset`` using the FastPersist §4.1 write path. The call
+    is the span ``write.stream``; ``WriteStats.seconds`` is its length."""
+    with obs.span("write.stream") as sp:
+        stats = _write_stream(path, segments, total, config, file_offset)
+    stats.seconds = sp.seconds
+    return stats
+
+
+def _write_stream(path, segments, total, cfg, file_offset) -> WriteStats:
     stats = WriteStats()
     align = cfg.alignment
     # O_DIRECT additionally requires the FILE offset to be aligned;
@@ -132,7 +140,6 @@ def write_stream(path: str, segments: Iterable[memoryview], total: int,
     tickets: list = [None] * nbuf
     crc: Optional[int] = 0 if cfg.checksum else None
 
-    t0 = time.perf_counter()
     seg_iter = iter(segments)
     # gated sources (chunked snapshots, DESIGN.md §10) expose
     # would_block(): instead of idling behind the gate with a
@@ -218,7 +225,6 @@ def write_stream(path: str, segments: Iterable[memoryview], total: int,
         written += len(tail)
 
     stats.bytes_written = written
-    stats.seconds = time.perf_counter() - t0
     stats.crc32 = crc
     stats.source_wait_seconds = float(getattr(segments, "wait_seconds",
                                               0.0))

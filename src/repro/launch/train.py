@@ -11,6 +11,7 @@ cut to fit (see chip_smoke.py).
 from __future__ import annotations
 
 import argparse
+import time
 
 from repro.configs import get_config, reduced as make_reduced
 from repro.core.checkpointer import FastPersistConfig
@@ -183,10 +184,11 @@ def main():
         # pre-engine directories need the old classes — DESIGN.md §4)
         start = tr.restore(tier=args.restore_tier)
         print(f"restored from step {start}")
-    state, metrics = tr.run(start_step=start)
-    import numpy as np
+    t0 = time.perf_counter()
+    state, metrics = tr.run(start_step=start)     # ends on the device
+    mean_iter = (time.perf_counter() - t0) / max(args.steps - start, 1)
     print(f"done: loss={float(metrics.get('loss', float('nan'))):.4f} "
-          f"mean_iter={np.mean(tr.iter_times)*1e3:.1f}ms "
+          f"mean_iter={mean_iter*1e3:.1f}ms "
           f"ckpt_stall={tr.ckpt_stall*1e3:.1f}ms")
 
 

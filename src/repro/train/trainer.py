@@ -21,8 +21,8 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 import jax
-import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.checkpointer import FastPersistConfig
 from repro.core.engine import CheckpointEngine, CheckpointSpec
@@ -108,7 +108,9 @@ class StepRecord:
     """What one train step left behind."""
     step: int
     loss: object                       # device scalar; float() syncs
-    ckpt_stall: float                  # seconds waiting on checkpoints
+    #: seconds the step waited on checkpoints: the previous save's
+    #: snapshot, and this step's ``engine.save`` (its throttle included)
+    ckpt_stall: float
     save: Optional[object] = None      # SaveHandle of this step's save
 
 
@@ -137,7 +139,6 @@ class Trainer:
         self.state: Optional[TrainState] = None
         self.engine: Optional[CheckpointEngine] = None
         self._retain = None
-        self.iter_times = []
         self.ckpt_stall = 0.0
         #: one :class:`StepRecord` per step run
         self.history: list = []
@@ -193,21 +194,35 @@ class Trainer:
         directory holds no committed step walks the tiers
         automatically — peer first when the policy replicates to
         peers, then the upload store (the lost-node recovery path —
-        DESIGN.md §8/§11)."""
+        DESIGN.md §8/§11).
+
+        Spans: ``restore`` round the call (tagged with the ``step``
+        asked for, -1 for the newest), with the children
+        ``restore.find`` (the newest committed step), ``restore.load``
+        (``engine.load``: read, CRC check, deserialize) and
+        ``restore.h2d`` (the copy onto the device, until it landed); the
+        bytes copied add to the counter ``restore.bytes``."""
         assert self.engine is not None, "no checkpoint engine configured"
         if tier not in ("local", "peer", "remote"):
             raise ValueError(f"tier must be 'local', 'peer' or "
                              f"'remote', got {tier!r}")
+        with obs.span("restore", step=-1 if step is None else step):
+            return self._restore(step, tier)
+
+    def _restore(self, step: Optional[int], tier: str) -> int:
         forced = tier != "local"
         use_tier = tier
-        if not forced and step is None \
-                and self.engine.latest_step() is None:
+        latest = None
+        if step is None and not forced:
+            with obs.span("restore.find"):
+                latest = self.engine.latest_step()
+        if step is None and latest is None and not forced:
             if self.engine.peer_replicator is not None:
                 use_tier = "peer"       # local tier empty/lost → peer
             elif self.engine.remote_store is not None:
                 use_tier = "remote"     # ... → remote
         if use_tier == "local":
-            step = step if step is not None else self.engine.latest_step()
+            step = step if step is not None else latest
             if step is None:
                 return 0
         # the pytree structure comes from the abstract state: resume
@@ -217,8 +232,9 @@ class Trainer:
         readers = (self.cfg.checkpoint.restore_readers
                    if self.cfg.checkpoint else None)
         try:
-            restored, manifest = self.engine.load(
-                step, like=like, parallel=readers, tier=use_tier)
+            with obs.span("restore.load"):
+                restored, manifest = self.engine.load(
+                    step, like=like, parallel=readers, tier=use_tier)
         except FileNotFoundError:
             # only the AUTOMATIC fallback may degrade to a fresh start;
             # an operator who explicitly asked for the peer/remote tier
@@ -238,8 +254,13 @@ class Trainer:
         self.engine.invalidate_arena()
         # jnp.array COPIES: a parallel load returns views into the
         # engine's read arena, which the next load would refill —
-        # the trainer's state must own its buffers (DESIGN.md §7)
-        self.state = jax.tree.map(jax.numpy.array, restored)
+        # the trainer's state must own its buffers (DESIGN.md §7).
+        # The restore returns once the state is on the device.
+        with obs.span("restore.h2d"):
+            self.state = jax.block_until_ready(
+                jax.tree.map(jax.numpy.array, restored))
+        obs.count("restore.bytes", sum(x.nbytes for x in
+                                       jax.tree.leaves(self.state)))
         extras = manifest.extras
         if "data" in extras:
             self.data = TokenStream.from_state(self.data.cfg, extras["data"])
@@ -251,15 +272,20 @@ class Trainer:
         return self.engine.save(self.state, step, extras)
 
     def run(self, start_step: int = 0):
+        """Steps ``start_step`` .. ``cfg.steps``. Spans per step:
+        ``train.data`` (the next batch), ``train.dispatch`` (enqueueing
+        the step) and, on a save step, ``train.save`` (waiting for the
+        new params, then ``engine.save``)."""
         if self.state is None:
             self.init_state()
         pol = self.cfg.checkpoint
         metrics = {}
+        t_log, logged = time.perf_counter(), start_step
         for step in range(start_step, self.cfg.steps):
-            t0 = time.perf_counter()
-            stall0 = self.ckpt_stall
+            stall = 0.0
             handle = None
-            batch = next(self.data)
+            with obs.span("train.data"):
+                batch = next(self.data)
             if self.engine is not None and self.engine.async_save:
                 # §4.3 sync point, chunk-granular (DESIGN.md §10): the
                 # previous checkpoint's device→arena SNAPSHOT must land
@@ -268,32 +294,38 @@ class Trainer:
                 # The NVMe writes keep overlapping this iteration; the
                 # engine's submit throttle + drain() stay the
                 # durability sync points.
-                t_w = time.perf_counter()
-                self.engine.wait_snapshot()
-                self.ckpt_stall += time.perf_counter() - t_w
-            self.state, metrics = self.train_step(self.state, batch)
+                stall += self.engine.wait_snapshot()
+            with obs.span("train.dispatch"):
+                self.state, metrics = self.train_step(self.state, batch)
             if pol and self.engine is not None \
                     and (step + 1) % pol.every == 0:
-                jax.block_until_ready(self.state.params)
-                handle = self._save(step + 1)
+                with obs.span("train.save", step=step + 1) as save:
+                    jax.block_until_ready(self.state.params)
+                    computing = time.perf_counter() - save.t0
+                    handle = self._save(step + 1)
+                # the wait for the step's own compute is not a stall
+                stall += save.seconds - computing
                 if self._retain is not None:
                     self._retain.after_commit()
-            self.iter_times.append(time.perf_counter() - t0)
+            self.ckpt_stall += stall
             self.history.append(StepRecord(step + 1, metrics["loss"],
-                                           self.ckpt_stall - stall0, handle))
+                                           stall, handle))
             if (step + 1) % self.cfg.log_every == 0:
-                print(f"step {step+1}: loss={float(metrics['loss']):.4f} "
-                      f"it={np.mean(self.iter_times[-self.cfg.log_every:])*1e3:.1f}ms")
+                loss = float(metrics["loss"])      # waits for the step
+                now = time.perf_counter()
+                it = (now - t_log) / (step + 1 - logged)
+                t_log, logged = now, step + 1
+                print(f"step {step+1}: loss={loss:.4f} it={it*1e3:.1f}ms")
         if self.engine is not None:
-            t_w = time.perf_counter()
-            self.engine.drain()     # commit stragglers, park the worker
-            # a CLEAN exit also flushes the upload AND peer tiers (the
-            # workers are daemon threads — returning now would abandon
-            # the tail generations' remote/peer COMMITs; a crash still
-            # degrades to the last fully-uploaded / fully-replicated
-            # generation, DESIGN §8/§11)
-            self.engine.wait_uploaded()
-            self.engine.wait_replicated()
-            self.ckpt_stall += time.perf_counter() - t_w
+            with obs.span("train.drain") as drain:
+                self.engine.drain()  # commit stragglers, park the worker
+                # a CLEAN exit also flushes the upload AND peer tiers
+                # (the workers are daemon threads — returning now would
+                # abandon the tail generations' remote/peer COMMITs; a
+                # crash still degrades to the last fully-uploaded /
+                # fully-replicated generation, DESIGN §8/§11)
+                self.engine.wait_uploaded()
+                self.engine.wait_replicated()
+            self.ckpt_stall += drain.seconds
         jax.block_until_ready(self.state.params)
         return self.state, metrics
