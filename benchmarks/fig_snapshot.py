@@ -59,9 +59,9 @@ class _EmuDeviceBlob:
     every byte-range read charges its transfer time at ``rate`` bytes/s
     as a GIL-released sleep — the CPU stays as free as it would behind
     a real DMA engine — and the rate is calibrated against the measured
-    write bandwidth so copy ≈ write (Eq. 1's boundary). ``_LeafBytes``
-    slices pieces through ``__getitem__``, so the chunked fill pays the
-    link per piece, exactly like a per-chunk D2H."""
+    write bandwidth so copy ≈ write (Eq. 1's boundary). The chunked
+    fill takes each leaf whole through ``__array__``, so it pays the
+    link once a leaf, like a whole-leaf D2H."""
 
     def __init__(self, host: np.ndarray, rate: float):
         self.host = host
@@ -70,14 +70,6 @@ class _EmuDeviceBlob:
         self.shape = host.shape
         self.size = host.size
         self.nbytes = host.nbytes
-
-    def reshape(self, *shape):
-        return _EmuDeviceBlob(self.host.reshape(*shape), self.rate)
-
-    def __getitem__(self, idx):
-        piece = self.host[idx]
-        time.sleep(piece.nbytes / self.rate)
-        return piece
 
     def __array__(self, dtype=None):
         time.sleep(self.nbytes / self.rate)

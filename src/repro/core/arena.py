@@ -38,8 +38,9 @@ the NEXT load on the same arena; copy them (``jnp.array`` /
 from __future__ import annotations
 
 import threading
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -53,6 +54,14 @@ PAGE = 4096
 #: is bit-preserving for these at scale=1; everything else host-compares)
 _DEV_DTYPES = ("float32", "bfloat16", "float16")
 
+#: how far the chunked fill's device-to-host transfers may run ahead of
+#: its copy into the arena (DESIGN.md §10): beside the arena, the host
+#: holds at most this many bytes of the state, or one leaf where a leaf
+#: is larger. The state is released only once its last leaf has landed,
+#: so what the look-ahead does not cover is copied into the arena while
+#: the train loop waits.
+LOOKAHEAD_BYTES = 2 << 30
+
 
 def _host_array(leaf) -> np.ndarray:
     """Device→host view of one leaf in the shared on-stream layout
@@ -61,46 +70,85 @@ def _host_array(leaf) -> np.ndarray:
     return np.atleast_1d(portable_view(np.asarray(leaf)))
 
 
-class _LeafBytes:
-    """Lazy byte-range source over one leaf, in on-stream layout.
+def _host_bytes(leaf) -> np.ndarray:
+    """One leaf's stream bytes on the host, whole, as flat uint8 (span
+    ``snapshot.xfer``: the blocking device-to-host transfer, or the wait
+    for one already under way)."""
+    with obs.span("snapshot.xfer"):
+        return _host_array(leaf).reshape(-1).view(np.uint8)
 
-    The chunked snapshot path (DESIGN.md §10) pulls a record's bytes in
-    pieces. For device (non-numpy) arrays each piece is sliced on device
-    first, so only that piece's bytes cross PCIe per call — the D2H
-    itself is chunk-granular, not just the staging copy. Numpy leaves
-    (and unsliceable hosts) fall back to one lazy full-record view."""
 
-    def __init__(self, leaf, nbytes: int):
-        self._leaf = leaf
-        self._n = int(nbytes)
-        self._host: Optional[np.ndarray] = None
-        self._flat = None
-        self._isz = 0
-        if not isinstance(leaf, np.ndarray) and hasattr(leaf, "dtype") \
-                and callable(getattr(leaf, "reshape", None)):
-            try:
-                self._flat = leaf.reshape(-1)
-                self._isz = np.dtype(str(leaf.dtype)).itemsize
-            except Exception:
-                self._flat = None
+def _own(x):
+    """A new jax array over ``x``'s device buffers, one new single-device
+    array per shard: jax caches a transfer's host copy on the array
+    objects it went through, so a pull through these leaves no copy on
+    ``x`` (which the caller and the save may hold until commit)."""
+    shards = [jax.make_array_from_single_device_arrays(
+        s.data.shape, jax.sharding.SingleDeviceSharding(s.device), [s.data])
+        for s in x.addressable_shards]
+    return jax.make_array_from_single_device_arrays(x.shape, x.sharding,
+                                                    shards)
 
-    def range(self, lo: int, hi: int) -> np.ndarray:
-        """uint8 view/copy of stream bytes [lo, hi) of this leaf.
-        Spans: ``snapshot.slice`` (dispatch of the piece's device slice)
-        and ``snapshot.xfer`` (the blocking device-to-host transfer)."""
-        partial = not (lo == 0 and hi == self._n)
-        if (self._flat is not None and partial and self._isz
-                and lo % self._isz == 0 and hi % self._isz == 0):
-            with obs.span("snapshot.slice"):
-                piece = self._flat[lo // self._isz:hi // self._isz]
-            with obs.span("snapshot.xfer"):
-                piece = np.ascontiguousarray(_host_array(piece))
-            return piece.reshape(-1).view(np.uint8)
-        if self._host is None:
-            with obs.span("snapshot.xfer"):
-                self._host = _host_array(self._leaf).reshape(-1).view(
-                    np.uint8)
-        return self._host[lo:hi]
+
+class _Pull:
+    """The whole-leaf pull of a chunked fill (DESIGN.md §10; a device
+    slice per piece would queue behind the train steps). Once the state
+    is ready (span ``snapshot.ready``), device-to-host transfers start in
+    stream order up to :data:`LOOKAHEAD_BYTES` ahead of the leaf being
+    copied into the arena, and each leaf's host bytes are dropped once
+    copied (:meth:`drop`). ``release`` is called as soon as the last leaf
+    has landed: from there on nothing reads the leaves. Host leaves are
+    copied, so a change made to them after the release is not saved."""
+
+    def __init__(self, leaves, sizes: Sequence[int],
+                 release: Optional[Callable[[], None]]):
+        self._src = [x for _path, x in leaves]
+        self._sizes = list(sizes)
+        self._host: List[Optional[np.ndarray]] = [None] * len(self._src)
+        self._started = 0          # leaves whose transfer has started
+        self._ahead = 0            # their bytes not yet dropped
+        self._release = release
+        with obs.span("snapshot.ready"):
+            jax.block_until_ready([x for x in self._src
+                                   if isinstance(x, jax.Array)])
+
+    def _start(self, i: int):
+        n = len(self._src)
+        while self._started < n and (
+                self._started <= i or self._ahead
+                + self._sizes[self._started] <= LOOKAHEAD_BYTES):
+            j = self._started
+            if isinstance(self._src[j], jax.Array):
+                self._src[j] = _own(self._src[j])
+                self._src[j].copy_to_host_async()
+            self._ahead += self._sizes[j]
+            self._started += 1
+
+    def _land(self, j: int) -> np.ndarray:
+        if self._host[j] is None:
+            x, self._src[j] = self._src[j], None
+            b = _host_bytes(x)
+            self._host[j] = b if isinstance(x, jax.Array) else b.copy()
+        return self._host[j]
+
+    def take(self, i: int) -> np.ndarray:
+        """Leaf ``i``'s stream bytes, as flat uint8."""
+        self._start(i)
+        if self._started == len(self._src):
+            for j in range(i, len(self._src)):
+                self._land(j)
+            self.finish()
+        return self._land(i)
+
+    def drop(self, i: int):
+        self._host[i] = None
+        self._ahead -= self._sizes[i]
+
+    def finish(self):
+        """Call ``release`` once, if it has not been called yet."""
+        release, self._release = self._release, None
+        if release is not None:
+            release()
 
 
 class SnapshotProgress:
@@ -369,11 +417,19 @@ class SerializeArena:
     def _fill(self, leaves, *, track_dirty: bool, dirty_block: int,
               device_dirty: bool = False,
               progress: Optional[SnapshotProgress] = None,
-              chunk_bytes: int = 0):
+              chunk_bytes: int = 0,
+              release: Optional[Callable[[], None]] = None):
         """Copy ``leaves`` into the laid-out arena (device→host), piece
         by piece when chunked. Dirty compare runs per piece BEFORE the
         copy-in overwrites the resident image; spans never cross record
-        boundaries (adjacent pieces of one record merge)."""
+        boundaries (adjacent pieces of one record merge).
+
+        A chunked fill without device masks pulls whole leaves a bounded
+        number of bytes ahead of its copy (:class:`_Pull`) and calls
+        ``release`` once the last leaf has landed: from there on nothing
+        reads ``leaves``, and the caller may donate them while the last
+        pieces are copied into the arena. Other fills take each record
+        whole as they reach it and call ``release`` at the end."""
         from repro.core.delta import dirty_byte_spans
         prev_valid = self.last_reused and self._image_valid
         self._image_valid = False
@@ -389,6 +445,9 @@ class SerializeArena:
             piece = max(chunk_bytes - chunk_bytes % dirty_block,
                         dirty_block)
         d2h = 0
+        pull = None
+        if progress is not None and not device_dirty:
+            pull = _Pull(leaves, [r.nbytes for r in self._records], release)
         for i, ((_path, leaf), dst, rec) in enumerate(
                 zip(leaves, self._buffers, self._records)):
             end = rec.offset + rec.nbytes
@@ -408,14 +467,14 @@ class SerializeArena:
                     progress.advance(end)
                 continue
             # host path: piece-granular compare+copy
-            src = _LeafBytes(leaf, rec.nbytes)
+            src = pull.take(i) if pull is not None else _host_bytes(leaf)
             dst8 = dst.reshape(-1).view(np.uint8)
             step = piece if piece else rec.nbytes
             rec_spans: list = []
             lo = 0
             while lo < rec.nbytes:
                 hi = min(lo + step, rec.nbytes)
-                pb = src.range(lo, hi)
+                pb = src[lo:hi]
                 if pb.size != hi - lo:
                     raise ValueError(
                         f"record {rec.name!r}: leaf yields {pb.size} "
@@ -437,6 +496,9 @@ class SerializeArena:
             if dirty is not None:
                 dirty.extend((rec.offset + off, length)
                              for off, length in rec_spans)
+            if pull is not None:
+                src = pb = None        # the last views of its bytes
+                pull.drop(i)
             d2h += rec.nbytes
             if device_dirty and self._device_eligible(rec, dirty_block):
                 # seed the device baseline so the NEXT fill can mask
@@ -450,6 +512,10 @@ class SerializeArena:
         self.last_d2h_bytes = d2h
         obs.count("snapshot.bytes", d2h)
         self._image_valid = True
+        if pull is not None:
+            pull.finish()
+        elif release is not None:
+            release()
 
     def serialize(self, leaves, treedef, track_dirty: bool = False,
                   dirty_block: int = 4096, device_dirty: bool = False):
@@ -479,7 +545,8 @@ class SerializeArena:
 
     def begin_snapshot(self, leaves, treedef, chunk_bytes: int, *,
                        track_dirty: bool = False, dirty_block: int = 4096,
-                       device_dirty: bool = False, step: int = -1):
+                       device_dirty: bool = False, step: int = -1,
+                       release: Optional[Callable[[], None]] = None):
         """Chunked-snapshot entry (DESIGN.md §10): lay out the stream
         (metadata only — no device transfer) and return
         ``(manifest, buffers, progress, fill)`` WITHOUT copying a byte.
@@ -492,7 +559,8 @@ class SerializeArena:
         re-raise at every ``wait_*`` site, which is how a mid-snapshot
         death aborts the writers before COMMIT. The fill is the span
         ``snapshot.fill`` (tagged ``step``), whose seconds become
-        ``progress.seconds``."""
+        ``progress.seconds``. ``release`` is called once the fill no
+        longer reads ``leaves`` (:meth:`_fill`)."""
         self._prepare(leaves, treedef)
         progress = SnapshotProgress(self._total, chunk_bytes)
         manifest = Manifest(self._records, self._total,
@@ -506,7 +574,7 @@ class SerializeArena:
                     self._fill(leaves, track_dirty=track_dirty,
                                dirty_block=dirty_block,
                                device_dirty=device_dirty, progress=progress,
-                               chunk_bytes=chunk_bytes)
+                               chunk_bytes=chunk_bytes, release=release)
             except BaseException as exc:   # noqa: BLE001 — parked, re-raised
                 progress.seconds = sp.seconds
                 progress.fail(exc)

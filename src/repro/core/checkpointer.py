@@ -328,17 +328,13 @@ class FastPersistCheckpointer:
         progress = None
         fill_thread = None
         if chunk_bytes:
+            # the fill calls `notify` once it no longer reads `state`;
+            # failures park in `progress`
             manifest, buffers, progress, fill = begin_snapshot(
                 state, self._arena, chunk_bytes, track_dirty=track,
                 dirty_block=self.config.dirty_block,
-                device_dirty=device_dirty, step=step)
-
-            def _fill_job():
-                fill()                     # failures park in `progress`
-                if notify is not None and not progress.failed:
-                    notify()
-
-            fill_thread = threading.Thread(target=_fill_job,
+                device_dirty=device_dirty, step=step, release=notify)
+            fill_thread = threading.Thread(target=fill,
                                            name="fp-snapshot", daemon=True)
             fill_thread.start()
         else:

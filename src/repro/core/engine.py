@@ -142,23 +142,24 @@ class SaveHandle:
         self._done.set()
 
     def _mark_snapshot(self):
-        # backend callback (bind_snapshot): the device→staging copy has
-        # fully landed; the write may still be in flight
+        # backend callback (bind_snapshot): the save no longer reads
+        # the state; the write may still be in flight
         self._snapshotted.set()
 
     def done(self) -> bool:
         return self._done.is_set()
 
     def snapshot_done(self) -> bool:
-        """True once the save's device→host snapshot has landed (the
+        """True once the save no longer reads the state's buffers (the
         write may still be in flight). Backends without snapshot
         signalling flip this together with :meth:`done`."""
         return self._snapshotted.is_set()
 
     def wait_snapshot(self, timeout: Optional[float] = None):
-        """Block until the snapshot (device→staging copy) of this save
-        has landed — the earliest point a training step that DONATES the
-        state's buffers may safely run (DESIGN.md §10). The write keeps
+        """Block until the state's buffers may be donated — the earliest
+        point a training step that DONATES them may safely run (DESIGN.md
+        §10): once the snapshot has every byte of the state on the host
+        or in the arena. The rest of the snapshot and the write keep
         overlapping that step; ``wait()`` remains the local durability
         point.
 
@@ -338,10 +339,10 @@ class CheckpointBackend:
 
     def bind_snapshot(self, callback):
         """Install the one-shot snapshot-complete callback for the NEXT
-        save (DESIGN.md §10): fire it once the device→staging copy has
-        fully landed, while the write may still be in flight. Backends
-        without a distinct snapshot stage ignore it — the engine then
-        treats snapshot-done as save-done."""
+        save (DESIGN.md §10): fire it once the save no longer reads the
+        state, while the write may still be in flight. Backends without
+        a distinct snapshot stage ignore it — the engine then treats
+        snapshot-done as save-done."""
 
     def after_commit(self, step: int, directory: str, marker: dict,
                      stats: SaveStats):
@@ -775,9 +776,9 @@ class CheckpointEngine:
                 shutil.rmtree(d)
             os.makedirs(d)
         # snapshot-granular sync (DESIGN.md §10): tell the backend to
-        # flip this handle's snapshot event as soon as the device→
-        # staging copy lands — binding happens here (on the serial save
-        # path) so queued saves never clobber each other's callback
+        # flip this handle's snapshot event as soon as the save no
+        # longer reads the state — binding happens here (on the serial
+        # save path) so queued saves never clobber each other's callback
         if handle is not None:
             self._backend.bind_snapshot(handle._mark_snapshot)
         published = False
@@ -888,13 +889,14 @@ class CheckpointEngine:
             raise err
 
     def wait_snapshot(self):
-        """Block until every in-flight save's device→host snapshot has
-        landed (DESIGN.md §10) — the chunk-granular half of the paper's
-        §4.3 sync point. After this, a train step may donate/overwrite
-        the state's device buffers while the WRITES still overlap its
-        forward/backward; full commits are still awaited by the save
-        throttle, :meth:`wait` and :meth:`drain`. Re-raises the first
-        failure of an already-failed save. No-op for sync backends.
+        """Block until every in-flight save lets the state's buffers be
+        donated (DESIGN.md §10): once its snapshot no longer reads them —
+        the snapshot-granular half of the paper's §4.3 sync point. After
+        this, a train step may donate/overwrite the state's device
+        buffers while the rest of the snapshot and the WRITES still
+        overlap its forward/backward; full commits are still awaited by
+        the save throttle, :meth:`wait` and :meth:`drain`. Re-raises the
+        first failure of an already-failed save. No-op for sync backends.
         Span ``engine.wait_snapshot``; returns the seconds it blocked."""
         with obs.span("engine.wait_snapshot") as sp:
             with self._lock:

@@ -134,18 +134,21 @@ def serialize(state, arena=None, track_dirty: bool = False,
 
 def begin_snapshot(state, arena, chunk_bytes: int, *,
                    track_dirty: bool = False, dirty_block: int = 4096,
-                   device_dirty: bool = False, step: int = -1):
+                   device_dirty: bool = False, step: int = -1,
+                   release=None):
     """Chunked-snapshot variant of :func:`serialize` (DESIGN.md §10):
     lays out the stream against ``arena`` without copying and returns
     ``(manifest, buffers, progress, fill)`` — the caller runs ``fill``
     on a snapshot worker and gates writer segments on ``progress``.
     Arena-only: the allocate-per-save path has no resident image to
-    fill piecewise."""
+    fill piecewise. ``release`` is called once the fill no longer reads
+    ``state``."""
     leaves, treedef = jax.tree_util.tree_flatten_with_path(state)
     return arena.begin_snapshot(leaves, treedef, chunk_bytes,
                                 track_dirty=track_dirty,
                                 dirty_block=dirty_block,
-                                device_dirty=device_dirty, step=step)
+                                device_dirty=device_dirty, step=step,
+                                release=release)
 
 
 def decode_record(rec: TensorRecord, raw) -> np.ndarray:

@@ -287,13 +287,13 @@ class Trainer:
             with obs.span("train.data"):
                 batch = next(self.data)
             if self.engine is not None and self.engine.async_save:
-                # §4.3 sync point, chunk-granular (DESIGN.md §10): the
-                # previous checkpoint's device→arena SNAPSHOT must land
-                # before the optimizer may update the params it captures
-                # (train_step donates its buffers — see pipeline docs).
-                # The NVMe writes keep overlapping this iteration; the
-                # engine's submit throttle + drain() stay the
-                # durability sync points.
+                # §4.3 sync point (DESIGN.md §10): train_step donates the
+                # state's buffers, so the previous checkpoint's snapshot
+                # must no longer read them — once every leaf has crossed
+                # to the host. The rest of the snapshot (the copy into
+                # the arena) and the NVMe writes keep overlapping this
+                # iteration; the engine's submit throttle + drain() stay
+                # the durability sync points.
                 stall += self.engine.wait_snapshot()
             with obs.span("train.dispatch"):
                 self.state, metrics = self.train_step(self.state, batch)

@@ -23,7 +23,7 @@ MODEL = dataclasses.replace(reduced(get_config("stablelm_1_6b")),
 SPANS = ["train.data", "train.dispatch", "train.save", "engine.save",
          "engine.throttle", "engine.wait_snapshot", "engine.wait",
          "engine.drain", "save.persist", "save.commit", "snapshot.fill",
-         "snapshot.slice", "snapshot.xfer", "snapshot.copy", "write.stream",
+         "snapshot.ready", "snapshot.xfer", "snapshot.copy", "write.stream",
          "restore", "restore.find", "restore.load", "restore.h2d",
          "load.alloc", "load.read", "load.verify", "load.materialize",
          "read.submit", "read.wait", "read.crc"]
@@ -85,6 +85,8 @@ def test_span_off_still_times():
 
 
 def test_save_spans_and_counters(tmp_path, recording):
+    """A chunked save waits once for the state, moves each leaf whole
+    (one transfer a leaf) and copies it into the arena piece by piece."""
     tr = _trainer(tmp_path, 4)
     tr.run()
     t = obs.totals()
@@ -95,9 +97,10 @@ def test_save_spans_and_counters(tmp_path, recording):
     pieces, partial = _pieces(tr.state)
     assert partial > 0
     assert sp["snapshot.fill"]["n"] == n_saves
-    assert sp["snapshot.xfer"]["n"] == n_saves * pieces
     assert sp["snapshot.copy"]["n"] == n_saves * pieces
-    assert sp["snapshot.slice"]["n"] == n_saves * partial
+    assert sp["snapshot.ready"]["n"] == n_saves
+    assert sp["snapshot.xfer"]["n"] == \
+        n_saves * len(jax.tree.leaves(tr.state))
     assert ct["snapshot.bytes"] == sum(st.d2h_bytes for st in stats)
     assert ct["snapshot.bytes"] == n_saves * stats[0].total_bytes
     # the Stats fields are the spans' own readings, not a second clock

@@ -9,7 +9,9 @@ Covers the three §10 contracts:
     device→arena copy lands, while the write is still in flight;
 plus the device-side dirty-mask path: delta chains built from kernel
 masks restore bit-exactly and move ~dirty bytes (not the stream) over
-the device→host link.
+the device→host link, and the whole-leaf pull of the chunked fill, which
+lets the state go once its last leaf has crossed and holds a bounded
+number of its bytes on the host.
 """
 import threading
 import time
@@ -301,6 +303,256 @@ def test_wait_snapshot_fires_for_monolithic_and_sync_backends():
                 h.result()
                 assert h.snapshot_done()
                 assert eng.latest_step() == 1
+
+
+# ------------------------------------------------- whole-leaf pull
+def _mixed_state(seed=0):
+    """bf16, f32 and int32 leaves, the largest over several 1 MiB chunks."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return {"params": jax.random.normal(k1, (640, 1024), jnp.bfloat16),
+            "master": jax.random.normal(k2, (300, 1024), jnp.float32),
+            "count": jax.random.randint(k3, (4099,), 0, 1 << 30, jnp.int32),
+            "step": jnp.int32(7)}
+
+
+def _stream_bytes(d, step):
+    import glob
+    import os
+    files = sorted(glob.glob(os.path.join(d, f"ckpt_{step:08d}", "*.bin")))
+    assert files
+    return b"".join(open(f, "rb").read() for f in files)
+
+
+@pytest.mark.parametrize("lookahead", [1 << 20, 256 << 20],
+                         ids=["below-state", "above-state"])
+def test_pull_writes_the_monolithic_bytes(monkeypatch, tmp_path,
+                                          lookahead):
+    """One state of bf16, f32 and int32 leaves saved chunked (the
+    whole-leaf pull, its look-ahead below or above the state's 2.5 MiB)
+    and monolithic: the same shard bytes on disk, and both load back
+    equal."""
+    monkeypatch.setattr(arena_mod, "LOOKAHEAD_BYTES", lookahead)
+    state = _mixed_state()
+    dirs = {}
+    for mb in (1, 0):
+        d = dirs[mb] = str(tmp_path / f"chunk{mb}")
+        spec = CheckpointSpec(directory=d, backend="fastpersist-pipelined",
+                              fp=_cfg(snapshot_chunk_mb=mb))
+        with CheckpointEngine(spec) as eng:
+            eng.save(state, 1, extras={"step": 1}).result()
+            loaded, _ = eng.load(1, like=state)
+            _assert_tree_equal(state, loaded)
+    assert _stream_bytes(dirs[1], 1) == _stream_bytes(dirs[0], 1)
+
+
+def _pull_events(monkeypatch, state, lookahead, chunk=64 << 10):
+    """Fill an arena from ``state`` inline and return the fill's events
+    in order: ("start", leaf), ("advance", watermark), ("release",)."""
+    monkeypatch.setattr(arena_mod, "LOOKAHEAD_BYTES", lookahead)
+    events = []
+    real_own, real_advance = arena_mod._own, SnapshotProgress.advance
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(state)
+    ids = {id(x): i for i, (_p, x) in enumerate(leaves)}
+
+    def own(x):
+        events.append(("start", ids[id(x)]))
+        return real_own(x)
+
+    def advance(self, watermark):
+        events.append(("advance", watermark))
+        return real_advance(self, watermark)
+
+    monkeypatch.setattr(arena_mod, "_own", own)
+    monkeypatch.setattr(SnapshotProgress, "advance", advance)
+    arena = SerializeArena()
+    man, bufs, progress, fill = arena.begin_snapshot(
+        leaves, treedef, chunk_bytes=chunk,
+        release=lambda: events.append(("release",)))
+    fill()
+    progress.wait_done()
+    v = ByteStreamView(bufs)
+    m2, b2 = SerializeArena().serialize(leaves, treedef)
+    assert bytes(v.read(0, v.total)) == bytes(
+        ByteStreamView(b2).read(0, m2.total_bytes))
+    return events, man.records
+
+
+def test_pull_runs_a_bounded_number_of_bytes_ahead(monkeypatch):
+    """With a 1.5 MiB look-ahead over leaves of 0.25-1.25 MiB, a leaf's
+    transfer starts only once the bytes started and not yet copied into
+    the arena fit the look-ahead (or it is the leaf being copied), and
+    each leaf starts once, in stream order."""
+    k = jax.random.PRNGKey(3)
+    state = {f"l{i}": jax.random.normal(k, (n * 256,), jnp.float32)
+             for i, n in enumerate([1024, 256, 1280, 512, 768, 256])}
+    events, records = _pull_events(monkeypatch, state, 1536 << 10)
+    starts = [e[1] for e in events if e[0] == "start"]
+    assert starts == list(range(len(records)))
+    copied = 0          # records whose last byte reached the arena
+    started = 0
+    mark = 0
+    for e in events:
+        if e[0] == "advance":
+            mark = e[1]
+            while copied < len(records) and \
+                    records[copied].offset + records[copied].nbytes <= mark:
+                copied += 1
+        elif e[0] == "start":
+            started = e[1] + 1
+            held = sum(r.nbytes for r in records[copied:started])
+            assert held <= 1536 << 10 or e[1] == copied, (e, held)
+
+
+@pytest.mark.parametrize("lookahead,copied_before", [
+    (1536 << 10, True), (256 << 20, False)],
+    ids=["below-state", "above-state"])
+def test_pull_releases_once_the_last_leaf_landed(monkeypatch, lookahead,
+                                                  copied_before):
+    """``release`` comes once, right after the last leaf's transfer
+    started and landed, and before the last record reaches the arena:
+    below the state's size the earlier leaves were copied first, above
+    it nothing was."""
+    k = jax.random.PRNGKey(4)
+    state = {f"l{i}": jax.random.normal(k, (n * 256,), jnp.float32)
+             for i, n in enumerate([1024, 1024, 512, 768])}
+    events, records = _pull_events(monkeypatch, state, lookahead)
+    at = events.index(("release",))
+    assert events.count(("release",)) == 1
+    last_start = max(i for i, e in enumerate(events) if e[0] == "start")
+    assert last_start < at
+    advances = [e[1] for e in events[:at] if e[0] == "advance"]
+    assert bool(advances) == copied_before
+    assert max(advances, default=0) < records[-1].offset
+
+
+def test_pull_releases_after_a_trailing_empty_leaf(monkeypatch):
+    """A state whose last record is empty still releases, once."""
+    state = {"a": jnp.ones((70000,), jnp.float32),
+             "z": jnp.zeros((0,), jnp.float32)}
+    events, _ = _pull_events(monkeypatch, state, 1 << 20)
+    assert events.count(("release",)) == 1
+
+
+def _gate_arena_copy(monkeypatch):
+    """Hold the fill at its first piece copied into the arena (after the
+    state's bytes reached the host) until the returned event is set."""
+    release, started = threading.Event(), threading.Event()
+    real_advance = SnapshotProgress.advance
+
+    def gated(self, watermark):
+        started.set()
+        assert release.wait(30), "test fill never released"
+        return real_advance(self, watermark)
+
+    monkeypatch.setattr(SnapshotProgress, "advance", gated)
+    return release, started
+
+
+def test_donating_update_after_wait_snapshot_keeps_the_saved_state(
+        monkeypatch, tmp_path):
+    """A donating update of the state, dispatched once ``wait_snapshot``
+    returned while the copy into the arena is held, leaves the committed
+    checkpoint equal to the state as it was at the save; the handle's
+    snapshot is done and the save is not."""
+    state = _mixed_state()
+    want = jax.tree.map(np.array, state)
+    update = jax.jit(lambda s: jax.tree.map(lambda x: x + 1, s),
+                     donate_argnums=0)
+    release, started = _gate_arena_copy(monkeypatch)
+    spec = CheckpointSpec(directory=str(tmp_path),
+                          backend="fastpersist-pipelined",
+                          fp=_cfg(snapshot_chunk_mb=1))
+    try:
+        with CheckpointEngine(spec) as eng:
+            h = eng.save(state, 1, extras={"step": 1})
+            eng.wait_snapshot()
+            assert h.snapshot_done()
+            new = jax.block_until_ready(update(state))
+            assert started.wait(30) and not h.done()
+            release.set()
+            h.result()
+            loaded, _ = eng.load(1, like=want)
+            _assert_tree_equal(want, loaded)
+            assert int(new["step"]) == int(want["step"]) + 1
+    finally:
+        release.set()
+
+
+def test_host_leaf_changed_after_release_keeps_the_saved_state(
+        monkeypatch, tmp_path):
+    """A numpy leaf changed in place once ``wait_snapshot`` returned, with
+    the copy into the arena still held, does not reach the checkpoint."""
+    state = dict(_mixed_state(), host=np.arange(5000, dtype=np.float32))
+    want = jax.tree.map(np.array, state)
+    release, started = _gate_arena_copy(monkeypatch)
+    spec = CheckpointSpec(directory=str(tmp_path),
+                          backend="fastpersist-pipelined",
+                          fp=_cfg(snapshot_chunk_mb=1))
+    try:
+        with CheckpointEngine(spec) as eng:
+            h = eng.save(state, 1)
+            eng.wait_snapshot()
+            state["host"] += 1
+            assert started.wait(30) and not h.done()
+            release.set()
+            h.result()
+            loaded, _ = eng.load(1, like=want)
+            _assert_tree_equal(want, loaded)
+    finally:
+        release.set()
+
+
+_SHARDED = r"""
+import os, sys, tempfile
+os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core import arena
+from repro.core.checkpointer import FastPersistConfig
+from repro.core.engine import CheckpointEngine, CheckpointSpec
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((4,), ('data',))
+state = {
+    'w': jax.device_put(jnp.arange(4096.0).reshape(64, 64),
+                        NamedSharding(mesh, P('data'))),
+    'r': jax.device_put(jnp.ones((8, 8), jnp.bfloat16),
+                        NamedSharding(mesh, P())),
+    'step': jnp.int32(3),                       # one device, uncommitted
+}
+for x in jax.tree.leaves(state):
+    o = arena._own(x)
+    assert o is not x and o.sharding == x.sharding, (o.sharding, x.sharding)
+    for a, b in zip(x.addressable_shards, o.addressable_shards):
+        assert a.device == b.device
+        assert a.data.unsafe_buffer_pointer() == b.data.unsafe_buffer_pointer()
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(x))
+with tempfile.TemporaryDirectory() as d:
+    spec = CheckpointSpec(directory=d, backend='fastpersist-pipelined',
+                          fp=FastPersistConfig(snapshot_chunk_mb=1))
+    with CheckpointEngine(spec) as eng:
+        eng.save(state, 1).result()
+        got, _ = eng.load(1, like=state)
+    for x, y in zip(jax.tree.leaves(state), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+print('ALL-OK')
+"""
+
+
+def test_sharded_pull_reads_each_shard_in_place():
+    """Four host devices: the pull's own view of a sharded, a replicated
+    and a single-device leaf keeps every shard on its device over the
+    same buffer, and a save of the sharded state loads back equal."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    r = subprocess.run([sys.executable, "-c", _SHARDED], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "ALL-OK" in r.stdout
 
 
 # ------------------------------------------------ device-side dirty masks
